@@ -430,6 +430,9 @@ def sharded_out_of_core_rows(full: bool = False):
     budget = (256 if full else 32) * 2 ** 20
     prog = _SHARDED_PROG.format(devices=devices, D=D, n=n, budget=budget)
     env = dict(os.environ)
+    # A CPU simulation of 4 devices by design: pinning the platform keeps
+    # the child off an accelerator that this process may hold.
+    env["JAX_PLATFORMS"] = "cpu"
     # repro is a namespace package (no __init__.py): locate it by __path__.
     src_dir = os.path.dirname(os.path.abspath(list(repro.__path__)[0]))
     env["PYTHONPATH"] = src_dir + (

@@ -1,99 +1,42 @@
-"""jax version-compatibility shim — the single import surface for API drift.
+"""The jax API surface shared across the codebase, in one place.
 
-Every jax API that moved, was renamed, or changed signature between the
-0.4.x and 0.6+ lines is resolved here once, so the rest of the codebase
-imports from ``repro.compat`` and never version-checks inline.
-
-Support matrix (verified against the pinned CI versions):
-
-  =====================  =======================  =========================
-  capability             jax 0.4.x (>=0.4.30)     jax 0.6+
-  =====================  =======================  =========================
-  shard_map              jax.experimental.        ``jax.shard_map`` with
-                         shard_map.shard_map      ``check_vma=``
-                         with ``check_rep=``
-  mesh axis types        (not available; meshes   ``jax.sharding.AxisType``
-                         are implicitly "auto")   passed via ``axis_types=``
-  ambient mesh context   legacy ``with mesh:``    ``jax.set_mesh(mesh)``
-                         resource-env manager
-  cost_analysis()        one-element list of      flat dict
-                         dicts
-  global array assembly  jax.make_array_from_     same API (stable); the
-                         single_device_arrays     helper additionally
-                                                  feature-detects and falls
-                                                  back to a sharded
-                                                  ``device_put``
-  multi-process init     jax.distributed.         same API; CPU collectives
-                         initialize (CPU          selected the same way
-                         collectives via the      (feature-detected — absent
-                         ``jax_cpu_collectives_   flag is skipped, never an
-                         implementation`` flag)   error)
-  cross-process fetch    jax.experimental.        same API (stable); the
-                         multihost_utils.         wrappers add the single-
-                         process_allgather        process fast paths
-  =====================  =======================  =========================
-
-Everything here is feature-detected (``hasattr``), not version-compared:
-point releases backport APIs and the jaxlib/jax pair may be mixed, so the
-presence of the symbol is the only reliable signal.
+The repository targets the installed jax line (0.9; see
+requirements-dev.txt). This module keeps the few wrappers that add
+behavior over jax's own API, so callers never repeat it: ``shard_map``
+under a ``check_replication`` name, meshes with explicit *auto* axis
+types, an ambient-mesh context that yields the mesh, global arrays
+assembled from per-shard host pieces, and multi-process helpers that
+degrade to cheap single-process paths when no cluster was initialized.
 """
 from __future__ import annotations
 
 import contextlib
 import functools
-import inspect
 from typing import Sequence
 
 import jax
 import jax.sharding
 import numpy as np
 
-HAS_TOP_LEVEL_SHARD_MAP = hasattr(jax, "shard_map")
-HAS_AXIS_TYPE = hasattr(jax.sharding, "AxisType")
-HAS_SET_MESH = hasattr(jax, "set_mesh")
-HAS_GLOBAL_ASSEMBLY = hasattr(jax, "make_array_from_single_device_arrays")
-HAS_DISTRIBUTED = hasattr(jax, "distributed") and hasattr(
-    jax.distributed, "initialize")
-
 
 # ---------------------------------------------------------------------------
 # shard_map
 # ---------------------------------------------------------------------------
 
-def _resolve_shard_map():
-    """-> (callable, name of the replication-check kwarg it accepts)."""
-    if HAS_TOP_LEVEL_SHARD_MAP:
-        fn = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as fn  # 0.4.x
-    params = inspect.signature(fn).parameters
-    for kw in ("check_vma", "check_rep"):
-        if kw in params:
-            return fn, kw
-    return fn, None  # neither: pass nothing (future-proof)
-
-
-_SHARD_MAP, _CHECK_KW = _resolve_shard_map()
-
-
 def shard_map(f=None, *, mesh, in_specs, out_specs, check_replication=True):
-    """Version-portable ``shard_map``.
-
-    ``check_replication`` maps onto ``check_vma=`` (jax >= 0.6) or
-    ``check_rep=`` (jax 0.4.x experimental). Usable directly or as a
-    decorator factory::
+    """``jax.shard_map`` with its replication check (``check_vma``) named
+    ``check_replication``. Usable directly or as a decorator factory::
 
         @functools.partial(compat.shard_map, mesh=mesh,
                            in_specs=..., out_specs=...,
                            check_replication=False)
         def run(local): ...
     """
-    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    if _CHECK_KW is not None:
-        kwargs[_CHECK_KW] = check_replication
+    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                  check_vma=check_replication)
     if f is None:
-        return functools.partial(_SHARD_MAP, **kwargs)
-    return _SHARD_MAP(f, **kwargs)
+        return functools.partial(jax.shard_map, **kwargs)
+    return jax.shard_map(f, **kwargs)
 
 
 # ---------------------------------------------------------------------------
@@ -101,18 +44,11 @@ def shard_map(f=None, *, mesh, in_specs, out_specs, check_replication=True):
 # ---------------------------------------------------------------------------
 
 def make_mesh(devices, axis_names: Sequence[str]) -> jax.sharding.Mesh:
-    """``Mesh`` over an ndarray of devices with *auto* axis types.
-
-    jax 0.6+ makes axis types explicit (``AxisType.Auto`` reproduces the
-    0.4.x behavior); 0.4.x has no ``axis_types=`` kwarg and every axis is
-    implicitly auto, so the two branches build the same mesh semantics.
-    """
+    """``Mesh`` over an ndarray of devices with every axis *auto*."""
     axes = tuple(axis_names)
-    if HAS_AXIS_TYPE:
-        from jax.sharding import AxisType
-        return jax.sharding.Mesh(devices, axes,
-                                 axis_types=(AxisType.Auto,) * len(axes))
-    return jax.sharding.Mesh(devices, axes)
+    return jax.sharding.Mesh(
+        devices, axes,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 # ---------------------------------------------------------------------------
@@ -121,19 +57,10 @@ def make_mesh(devices, axis_names: Sequence[str]) -> jax.sharding.Mesh:
 
 @contextlib.contextmanager
 def set_mesh(mesh: jax.sharding.Mesh):
-    """Install ``mesh`` as the ambient mesh for the duration.
-
-    jax 0.6+: ``jax.set_mesh`` (explicit-sharding aware). jax 0.4.x: the
-    legacy ``with mesh:`` resource-env context (sufficient for the
-    NamedSharding / shard_map paths used in this codebase, which always
-    pass the mesh explicitly as well).
-    """
-    if HAS_SET_MESH:
-        with jax.set_mesh(mesh):
-            yield mesh
-    else:
-        with mesh:
-            yield mesh
+    """Install ``mesh`` as the ambient mesh (``jax.set_mesh``) for the
+    duration; yields the mesh."""
+    with jax.set_mesh(mesh):
+        yield mesh
 
 
 # ---------------------------------------------------------------------------
@@ -154,11 +81,6 @@ def global_array_from_shards(mesh: jax.sharding.Mesh, pspec,
     are asynchronous, so the sources' prefetch ring overlaps them with
     compute). Axes of ``mesh`` not named in ``pspec`` replicate each piece
     across their devices.
-
-    The assembly API is stable across both supported jax lines; it is
-    still feature-detected, with a host-concatenate + sharded
-    ``device_put`` fallback, so this helper can never strand the streamed
-    executors on an API-less build.
 
     **Multi-process:** under ``jax.distributed`` each process addresses
     only its own devices, so a piece whose shard lives on *another*
@@ -183,13 +105,6 @@ def global_array_from_shards(mesh: jax.sharding.Mesh, pspec,
                 "(pad every shard's piece to one common block shape)")
     shape = (rows * len(arrs),) + ref.shape[1:]
     sharding = jax.sharding.NamedSharding(mesh, pspec)
-    if not HAS_GLOBAL_ASSEMBLY:  # pragma: no cover - both CI lines have it
-        if any(a is None for a in arrs):
-            raise RuntimeError(
-                "the sharded device_put fallback concatenates on the host "
-                "and needs every piece; None (remote) pieces require "
-                "jax.make_array_from_single_device_arrays")
-        return jax.device_put(np.concatenate(arrs, axis=0), sharding)
     shards = []
     for dev, idx in sharding.addressable_devices_indices_map(shape).items():
         start = idx[0].start or 0
@@ -227,53 +142,40 @@ def enable_cpu_collectives(impl: str = "gloo") -> bool:
 
     Must run *before* the CPU backend is first initialized — without it,
     multi-process programs on CPU fail with "Multiprocess computations
-    aren't implemented on the CPU backend". The flag exists on both
-    supported lines; feature-detected (an absent/renamed flag returns
-    False rather than raising) because it is exactly the kind of
-    config-surface drift this module exists to absorb.
+    aren't implemented on the CPU backend". Returns True once selected.
     """
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", impl)
-        return True
-    except (AttributeError, ValueError):  # pragma: no cover - drift guard
-        return False
+    jax.config.update("jax_cpu_collectives_implementation", impl)
+    return True
 
 
 def distributed_initialize(coordinator_address: str, num_processes: int,
                            process_id: int, *,
                            initialization_timeout: float | None = None,
                            cpu_collectives: str | None = "gloo") -> None:
-    """``jax.distributed.initialize`` with the version drift absorbed.
+    """``jax.distributed.initialize`` with the coordinator named explicitly
+    (so nothing is looked up from a cluster environment).
 
     Selects the CPU collectives backend first (set ``cpu_collectives=None``
     on accelerator clusters where XLA's native collectives apply), then
-    initializes the distributed runtime. ``initialization_timeout`` is
-    forwarded only where the jax line supports the kwarg — on lines
-    without it the coordinator default applies.
+    initializes the distributed runtime. ``initialization_timeout=None``
+    keeps jax's default.
     """
-    if not HAS_DISTRIBUTED:  # pragma: no cover - both CI lines have it
-        raise RuntimeError(
-            "this jax build has no jax.distributed.initialize — "
-            "multi-process execution is unavailable")
     if cpu_collectives is not None:
         enable_cpu_collectives(cpu_collectives)
     kwargs = dict(coordinator_address=coordinator_address,
                   num_processes=int(num_processes),
                   process_id=int(process_id))
     if initialization_timeout is not None:
-        params = inspect.signature(jax.distributed.initialize).parameters
-        if "initialization_timeout" in params:
-            kwargs["initialization_timeout"] = int(initialization_timeout)
+        kwargs["initialization_timeout"] = int(initialization_timeout)
     jax.distributed.initialize(**kwargs)
 
 
 def distributed_shutdown() -> None:
     """Tear down the distributed runtime; a no-op when none is active."""
-    if HAS_DISTRIBUTED and hasattr(jax.distributed, "shutdown"):
-        try:
-            jax.distributed.shutdown()
-        except RuntimeError:  # pragma: no cover - already down
-            pass
+    try:
+        jax.distributed.shutdown()
+    except RuntimeError:  # pragma: no cover - already down
+        pass
 
 
 def process_index() -> int:
@@ -320,21 +222,15 @@ def replicated_array(mesh: jax.sharding.Mesh, x) -> jax.Array:
     """``x`` replicated across every device of ``mesh``.
 
     Single-process this is just ``device_put`` with a replicated
-    ``NamedSharding``. Multi-process, ``device_put`` cannot target
-    non-addressable devices on the 0.4.x line, so the replica set is
-    assembled from per-local-device copies via
-    ``make_array_from_single_device_arrays`` — every process holds the
-    same host value (replicated-by-construction SPMD drivers), so no
-    data crosses processes.
+    ``NamedSharding``. Multi-process, the replica set is assembled from
+    per-local-device copies via ``make_array_from_single_device_arrays``
+    — every process holds the same host value (replicated-by-construction
+    SPMD drivers), so no data crosses processes.
     """
     x = np.asarray(x)
     sharding = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
     if process_count() == 1:
         return jax.device_put(x, sharding)
-    if not HAS_GLOBAL_ASSEMBLY:  # pragma: no cover - both CI lines have it
-        raise RuntimeError(
-            "multi-process replication requires "
-            "jax.make_array_from_single_device_arrays")
     local = [d for d in mesh.devices.flat
              if d.process_index == process_index()]
     arrs = [jax.device_put(x, d) for d in local]
@@ -367,12 +263,5 @@ def local_shard_indices(mesh: jax.sharding.Mesh, pspec,
 # ---------------------------------------------------------------------------
 
 def cost_analysis(compiled) -> dict:
-    """Flat cost dict from a compiled executable.
-
-    jax 0.4.x returns a one-element list of dicts (one per program);
-    0.6+ returns the dict directly. Empty dict when unavailable.
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
+    """Cost dict of a compiled executable (empty when unavailable)."""
+    return compiled.cost_analysis() or {}

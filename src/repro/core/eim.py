@@ -46,10 +46,11 @@ what makes the parity bitwise rather than approximate.
 
 ``impl`` reaches every distance pass of both forms: the executors' filter
 rounds dispatch through ``engine.filter_tile_update`` /
-``engine.eim_filter_block``, so on backends with a native Pallas lowering
-(``impl="auto"`` on TPU, feature-detected GPU) Rounds 2–3 run as the fused
-one-VMEM-pass streamed tile (``kernels/fused_stream.py``) — bitwise the
-ref oracle, as the parity suite pins in interpret mode on CPU.
+``engine.eim_filter_block``, so on TPU (``impl="auto"``) Rounds 2–3 run
+as the fused one-VMEM-pass streamed tile (``kernels/fused_stream.py``),
+or the center-tiled kernel when the sampled centers overflow VMEM — the
+ref oracle's arithmetic, as the parity suite pins in interpret mode on
+CPU.
 """
 from __future__ import annotations
 

@@ -65,9 +65,6 @@ simulation (per-machine update + per-machine top-k, merged like the
 MapReduce shuffle would). Both produce bitwise-identical ``d_s`` and pivot
 for the same inputs on the ref backend — value reductions (min, top-k
 values) are blocking-invariant.
-
-jax version note: the mesh path is built on ``repro.compat.shard_map`` and
-runs unchanged on jax 0.4.x and 0.6+.
 """
 from __future__ import annotations
 
@@ -219,8 +216,12 @@ def _vmapped(fn: BlockFn):
     return jax.jit(jax.vmap(fn))
 
 
+@functools.partial(jax.jit, static_argnums=1)
 def _block(points: jnp.ndarray, m: int):
-    """Pad & reshape (n,d) -> (m, ceil(n/m), d) plus validity mask."""
+    """Pad & reshape (n,d) -> (m, ceil(n/m), d) plus validity mask.
+
+    Jitted: eagerly, a pad (even a zero-width one) and a reshape each copy
+    the points, and three copies of a 6.4 GB input do not fit one chip."""
     n, d = points.shape
     per = -(-n // m)
     pad = per * m - n
@@ -924,10 +925,9 @@ class MeshExecutor(Executor):
 
     def _replicated(self, arr) -> jnp.ndarray:
         if compat.process_count() > 1:
-            # device_put to a replicated NamedSharding cannot target the
-            # other processes' devices on the 0.4.x line — assemble the
-            # replica set from per-local-device copies instead (the host
-            # value is identical on every process by SPMD construction).
+            # Assemble the replica set from per-local-device copies (the
+            # host value is identical on every process by SPMD
+            # construction).
             return compat.replicated_array(self.mesh,
                                            np.asarray(arr, np.float32))
         return jax.device_put(jnp.asarray(arr, jnp.float32),

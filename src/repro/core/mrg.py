@@ -196,10 +196,6 @@ def mrg_distributed(
     may exceed what an un-chunked (n/m, k) block would allow.
 
     Returns ``(centers (k,d) replicated, radius2 ())``.
-
-    Version note: built on ``repro.compat.shard_map`` — runs on jax 0.4.x
-    (``jax.experimental.shard_map``, ``check_rep``) and 0.6+
-    (``jax.shard_map``, ``check_vma``) unchanged.
     """
     ex = MeshExecutor(mesh, shard_axes=shard_axes, hierarchical=hierarchical)
     centers, r2, _ = ex.mrg(as_source(points), k, impl=impl, chunk=chunk)

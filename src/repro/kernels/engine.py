@@ -3,7 +3,8 @@
 This module is the real execution layer behind ``repro.kernels.ops`` (which
 is kept as a thin façade for API stability). It owns:
 
-  * ``impl`` resolution — Pallas on TPU, pure-jnp reference elsewhere;
+  * ``impl`` resolution — Pallas on TPU, pure-jnp reference elsewhere
+    (interpret-mode Pallas only on request, and only on CPU);
   * shape padding — kernels need block-divisible sizes, callers don't;
   * **row-chunk streaming** — the paper-motivated memory model below.
 
@@ -15,9 +16,10 @@ parameter every op streams row-blocks of at most ``chunk`` points:
 
   * reference path — a ``lax.scan`` over ``(chunk, d)`` tiles, so peak
     working memory is O(chunk·(m + d) + m·d) regardless of n;
-  * Pallas path — ``chunk`` caps the row block size ``bn`` fed to the grid
-    (TPU grids already execute tiles sequentially, so the grid *is* the
-    stream; ``chunk`` bounds the per-step VMEM footprint).
+  * Pallas path — the grid walks row tiles of a fixed ``ROW_TILE``
+    multiple (TPU grids already execute tiles sequentially, so the grid
+    *is* the stream); ``chunk`` can only lower the tile to one
+    ``ROW_TILE``, the least row block the TPU lowering accepts.
 
 ``chunk=None`` (default) preserves the legacy un-chunked behavior exactly.
 ``memory_budget`` (bytes) derives a chunk from the working-set model
@@ -25,10 +27,6 @@ parameter every op streams row-blocks of at most ``chunk`` points:
 (parity-tested in tests/test_engine.py): elementwise minima are bitwise
 identical, and cross-chunk arg-reductions resolve ties to the first
 occurrence exactly like ``jnp.argmax``/``argmin``.
-
-jax version support: this module is pure jnp/lax/pallas and runs unchanged
-on jax 0.4.x and 0.6+ (the version-sensitive mesh/shard_map surface lives
-in ``repro.compat``).
 """
 from __future__ import annotations
 
@@ -45,6 +43,7 @@ from .assign import DEFAULT_BN as _A_BN
 from .assign import assign_nearest_blocks
 from .fused_argfar import DEFAULT_BN as _F_BN
 from .fused_argfar import fused_min_argmax_blocks
+from .fused_stream import ROW_TILE
 from .pairwise import DEFAULT_BM as _P_BM
 from .pairwise import DEFAULT_BN as _P_BN
 from .pairwise import pairwise_dist2 as _pairwise_pallas
@@ -59,58 +58,32 @@ _NEG = np.float32(-3.4e38)
 # impl / chunk resolution
 # ---------------------------------------------------------------------------
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-@functools.lru_cache(maxsize=None)
 def _pallas_native() -> bool:
-    """Can ``pl.pallas_call`` lower *natively* on the default backend?
-
-    TPU always lowers (Mosaic). On GPU the Triton lowering exists only on
-    CUDA jaxlibs of sufficient vintage — keying on the backend *name*
-    alone (the old ``_on_tpu`` test) both under-enables (GPU never got the
-    kernels) and would over-enable (ROCm / old jaxlibs raise at lowering
-    time) — so GPU is feature-detected by compiling one trivial kernel.
-    Anything else (CPU) has no native lowering; interpret mode remains
-    available via ``impl="pallas"``. Cached per process — backend choice
-    is fixed at jax init.
-    """
-    backend = jax.default_backend()
-    if backend == "tpu":
-        return True
-    if backend == "gpu":
-        try:
-            from jax.experimental import pallas as pl
-
-            def _probe(x_ref, o_ref):
-                o_ref[...] = x_ref[...] + 1.0
-
-            out = pl.pallas_call(
-                _probe,
-                out_shape=jax.ShapeDtypeStruct((8, 128), jnp.float32),
-            )(jnp.zeros((8, 128), jnp.float32))
-            jax.block_until_ready(out)
-            return True
-        except Exception:
-            return False
-    return False
+    """Do Pallas kernels compile natively (Mosaic) on the default backend?
+    Only on TPU; the backend is fixed at jax init."""
+    return jax.default_backend() == "tpu"
 
 
 def _resolve(impl: str):
     """-> (use_pallas, interpret)
 
-    ``auto`` uses the Pallas kernels wherever they lower natively (TPU
-    Mosaic, feature-detected GPU Triton) and the jnp reference elsewhere —
-    never interpret mode, which is a correctness tool, not a fast path.
-    ``pallas`` forces the kernels, gracefully falling back to interpret
-    mode on backends without a native lowering (the form the CPU CI parity
-    tests exercise). ``ref`` forces the oracle.
+    ``auto`` uses the Pallas kernels on TPU and the jnp reference
+    elsewhere. ``pallas`` forces the kernels: native on TPU, interpret
+    mode on CPU (the form the CPU parity tests exercise) — interpret mode
+    is a CPU correctness tool and never runs on an accelerator. ``ref``
+    forces the oracle.
     """
     if impl == "auto":
         return (True, False) if _pallas_native() else (False, False)
     if impl == "pallas":
-        return True, not _pallas_native()
+        if _pallas_native():
+            return True, False
+        if jax.default_backend() != "cpu":
+            raise ValueError(
+                f"impl='pallas' has no native lowering on "
+                f"{jax.default_backend()!r}, and interpret mode runs on "
+                "CPU only")
+        return True, True
     if impl == "ref":
         return False, False
     raise ValueError(f"unknown impl {impl!r}")
@@ -177,8 +150,8 @@ def _blocks(a: jnp.ndarray, chunk: int, fill: float):
 
 
 def _pallas_bn(bn: int, n: int, chunk: int | None) -> int:
-    """Row block for the Pallas grid: ≤ bn, ≤ chunk (rounded up to the 8-row
-    sublane minimum), never below 8.
+    """Row block for the 2-D-output pairwise grid: ≤ bn, ≤ chunk (rounded
+    up to the 8-row sublane minimum), never below 8.
 
     The round-*up* is only safe because budget-derived chunks arrive
     pre-floored to a sublane multiple (``resolve_chunk(..., sublane=8)``),
@@ -189,6 +162,24 @@ def _pallas_bn(bn: int, n: int, chunk: int | None) -> int:
     if chunk is not None:
         bn_ = min(bn_, max(8, -(-chunk // 8) * 8))
     return bn_
+
+
+def _row_bn(bn: int, n: int, chunk: int | None, interpret: bool) -> int:
+    """Row block for the kernels with per-row vector operands (argfar,
+    assign, the fused streamed tiles).
+
+    Native (TPU): the largest ``ROW_TILE`` multiple ≤ bn and ≤ chunk, never
+    below one ``ROW_TILE`` — the vectors' lane-dense ``(bn/128, 128)``
+    blocks are (8, 128)-aligned, as the TPU lowering requires, only then.
+    Interpret mode (CPU) has no layouts, so it keeps the 8-row sublane
+    granularity of ``_pallas_bn``: the CPU parity tests then walk many
+    small tiles, and a chunked ref scan and the tile grid split rows
+    alike. Callers pad rows up to a multiple of the block.
+    """
+    if interpret:
+        return _pallas_bn(bn, n, chunk)
+    cap = bn if chunk is None else min(bn, chunk)
+    return max(ROW_TILE, cap // ROW_TILE * ROW_TILE)
 
 
 # ---------------------------------------------------------------------------
@@ -245,15 +236,15 @@ def fused_min_argmax(x, c, min_d2, *, impl: str = "auto",
     chunk = resolve_chunk(n, 1, d, chunk=chunk, memory_budget=memory_budget,
                           sublane=8 if use_pallas else None)
     if use_pallas:
-        bn_ = _pallas_bn(bn, n, chunk)
+        bn_ = _row_bn(bn, n, chunk, interpret)
         xp, _ = _pad_rows(x, bn_, 0.0)
         # Padded rows get -inf min-dist so they never become the farthest
         # point and their updated min stays -inf.
         mdp, _ = _pad_rows(min_d2, bn_, -_BIG)
         new_md, bmax, barg = fused_min_argmax_blocks(xp, c, mdp, bn=bn_,
                                                      interpret=interpret)
-        blk = jnp.argmax(bmax[:, 0])
-        return new_md[:n], bmax[blk, 0], barg[blk, 0]
+        blk = jnp.argmax(bmax[:, 0, 0])
+        return new_md[:n], bmax[blk, 0, 0], barg[blk, 0, 0]
     if chunk is None or chunk >= n:
         return ref.fused_min_argmax(x, c, min_d2)
     xb, n0 = _blocks(x, chunk, 0.0)
@@ -292,15 +283,15 @@ def assign_nearest(x, c, *, impl: str = "auto", chunk: int | None = None,
     chunk = resolve_chunk(n, m, d, chunk=chunk, memory_budget=memory_budget,
                           sublane=8 if use_pallas else None)
     if use_pallas:
-        bn_ = _pallas_bn(bn, n, chunk)
-        bm_ = min(bm, max(8, m))
+        bn_ = _row_bn(bn, n, chunk, interpret)
+        bm_ = min(bm, -(-m // 8) * 8)
         xp, _ = _pad_rows(x, bn_, 0.0)
         # Pad centers at +inf-ish distance: fill with a huge coordinate so
         # padded centers are never nearest.
         cp, _ = _pad_rows(c, bm_, 1e18)
         idx, d2 = assign_nearest_blocks(xp, cp, bn=bn_, bm=bm_,
                                         interpret=interpret)
-        return idx[:n, 0], d2[:n, 0]
+        return idx[:n], d2[:n]
     if chunk is None or chunk >= n:
         return ref.assign_nearest(x, c)
     xb, n0 = _blocks(x, chunk, 0.0)
@@ -791,23 +782,28 @@ def _source_weights(source, start: int, rows: int) -> np.ndarray:
 # compile-count proxy). The ref branches are the bitwise oracle; the tile
 # kernels reproduce their bits exactly (rows-only tiling — see the
 # fused_stream module docstring for why that makes bitwise possible).
+#
+# The fused tiles keep every center resident in VMEM. A center set too
+# large for that (``fused_stream.fits_vmem``) takes the unfused branch,
+# whose ``assign_nearest`` is the center-tiled kernel on TPU: the choice
+# is made from the shapes, and both branches run on the device.
 
-def _stream_bn(rows: int, chunk: int | None) -> int:
-    """Row tile for the fused streamed kernels: ≤ the kernel default,
-    ≤ chunk (the per-pass VMEM knob, floored to the 8-row sublane so an
-    explicit chunk is never exceeded), never below 8, and never a
-    whole-grid overshoot of a small block."""
-    bn = min(fused_stream.DEFAULT_BN, max(8, -(-rows // 8) * 8))
-    if chunk is not None:
-        bn = min(bn, max(8, (chunk // 8) * 8))
-    return bn
+def _stream_tiles(impl: str, chunk: int | None, rows: int, m: int, d: int):
+    """-> (fused, interpret, bn) for a streamed fold of ``rows``-row blocks
+    against ``m`` centers: ``fused`` when the Pallas path is selected and
+    the centers fit VMEM."""
+    use_pallas, interpret = _resolve(impl)
+    bn = _row_bn(fused_stream.DEFAULT_BN, -(-rows // 8) * 8, chunk,
+                 interpret)
+    fused = use_pallas and fused_stream.fits_vmem(bn, m, d)
+    return fused, interpret, bn
 
 
 def _padded_rows(rows: int, bn: int) -> int:
     return -(-rows // bn) * bn
 
 
-def _filter_update_tiles(blk, c, d_blk, h_blk, rank: int, chunk: int | None,
+def _filter_update_tiles(blk, c, d_blk, h_blk, rank: int, bn: int,
                          interpret: bool, w_blk=None):
     """Traced helper: pad one block to the tile grid and run the fused
     filter kernel. Returns ``(d_new (rows,), tops (tiles, rank))`` — the
@@ -816,7 +812,6 @@ def _filter_update_tiles(blk, c, d_blk, h_blk, rank: int, chunk: int | None,
     weights) routes to the weighted sibling kernel, whose extra VMEM
     operand gates ``w <= 0`` rows out of candidacy."""
     rows = blk.shape[0]
-    bn = _stream_bn(rows, chunk)
     rows_p = _padded_rows(rows, bn)
     pad = rows_p - rows
     blk_p = jnp.pad(blk, ((0, pad), (0, 0)))
@@ -849,11 +844,12 @@ def filter_tile_update(blk, c, d_blk, h_blk, *, rank: int,
     rows out of top-k candidacy; ``w_blk=None`` runs the exact pre-weights
     program.
     """
-    use_pallas, interpret = _resolve(impl)
+    fused, interpret, bn = _stream_tiles(impl, chunk, blk.shape[0],
+                                         c.shape[0], blk.shape[1])
     r = min(rank, d_blk.shape[0])
-    if use_pallas:
+    if fused:
         d_new, tops = _filter_update_tiles(blk, c, d_blk, h_blk, rank,
-                                           chunk, interpret, w_blk=w_blk)
+                                           bn, interpret, w_blk=w_blk)
         return d_new, jax.lax.top_k(tops.reshape(-1), r)[0]
     _, dn = assign_nearest(blk, c, impl=impl, chunk=chunk)
     d_new = jnp.minimum(d_blk, dn)
@@ -912,12 +908,12 @@ def fold_min_d2(source, c, *, impl: str = "auto", chunk: int | None = None,
     rows = resolve_block_rows(source.n, source.d, block_rows=block_rows,
                               memory_budget=memory_budget,
                               prefetch=prefetch or DEFAULT_PREFETCH)
-    use_pallas, interpret = _resolve(impl)
-    if use_pallas:
+    fused, interpret, bn = _stream_tiles(impl, chunk, rows, c.shape[0],
+                                         source.d)
+    if fused:
         # Fused tile path: the filter kernel with rank=1 and a +BIG d_s
         # carry IS the per-tile max of min-distances; the validity mask
         # gates padded lanes, so one compilation serves the ragged tail.
-        bn = _stream_bn(rows, chunk)
         rows_p = _padded_rows(rows, bn)
         d_big = jnp.full((rows_p,), _BIG)
         best = None
@@ -968,11 +964,11 @@ def fold_top_k_min_d2(source, c, rank: int, *, impl: str = "auto",
     rows = resolve_block_rows(source.n, source.d, block_rows=block_rows,
                               memory_budget=memory_budget,
                               prefetch=prefetch or DEFAULT_PREFETCH)
-    use_pallas, interpret = _resolve(impl)
+    fused, interpret, bn = _stream_tiles(impl, chunk, rows, c.shape[0],
+                                         source.d)
     top = top_k_init(rank)
     off = 0
-    if use_pallas:
-        bn = _stream_bn(rows, chunk)
+    if fused:
         rows_p = _padded_rows(rows, bn)
         d_big = jnp.full((rows_p,), _BIG)
         for blk in _source_blocks(source, rows, prefetch):
@@ -1026,10 +1022,10 @@ def assign_nearest_source(source, c, *, impl: str = "auto",
     rows = resolve_block_rows(source.n, source.d, block_rows=block_rows,
                               memory_budget=memory_budget,
                               prefetch=prefetch or DEFAULT_PREFETCH)
-    use_pallas, interpret = _resolve(impl)
+    fused, interpret, bn = _stream_tiles(impl, chunk, rows, c.shape[0],
+                                         source.d)
     off = 0
-    if use_pallas:
-        bn = _stream_bn(rows, chunk)
+    if fused:
         rows_p = _padded_rows(rows, bn)
         for blk in _source_blocks(source, rows, prefetch):
             nb = blk.shape[0]
@@ -1072,19 +1068,17 @@ def argmin_dist2_over_source(source, c, *, impl: str = "auto",
     rows = resolve_block_rows(source.n, source.d, block_rows=block_rows,
                               memory_budget=memory_budget,
                               prefetch=prefetch or DEFAULT_PREFETCH)
-    use_pallas, interpret = _resolve(impl)
+    fused, interpret, bn = _stream_tiles(impl, chunk, rows, m, source.d)
     best_d = jnp.full((m,), _BIG)
     best_i = jnp.zeros((m,), jnp.int32)
     off = 0
-    if use_pallas:
-        bn = _stream_bn(rows, chunk)
+    if fused:
         rows_p = _padded_rows(rows, bn)
         for blk in _source_blocks(source, rows, prefetch):
             nb = blk.shape[0]
             blk_p = jnp.pad(blk, ((0, rows_p - nb), (0, 0)))
-            vm = (jnp.arange(rows_p) < nb).astype(jnp.float32)
             bd, bi = fused_stream.fused_argmin_blocks(
-                blk_p, c, vm, bn=bn, interpret=interpret)
+                blk_p, c, np.int32(nb), bn=bn, interpret=interpret)
             take = bd < best_d
             best_d = jnp.where(take, bd, best_d)
             best_i = jnp.where(take, bi + off, best_i)

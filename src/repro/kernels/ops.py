@@ -8,9 +8,9 @@ aliases, so signatures, defaults, and docstrings have a single home in
 engine.py.
 
 ``impl`` resolution:
-  * ``"auto"``   — Pallas on TPU, reference jnp elsewhere (CPU/GPU).
-  * ``"pallas"`` — force the Pallas kernel (interpret mode off-TPU; this is
-                   the path tests use to validate kernels on CPU).
+  * ``"auto"``   — Pallas on TPU, reference jnp elsewhere.
+  * ``"pallas"`` — force the Pallas kernel (interpret mode on CPU; this is
+                   the path tests use to validate kernels there).
   * ``"ref"``    — force the pure-jnp oracle.
 
 New in the chunked engine (all optional, default = legacy behavior):

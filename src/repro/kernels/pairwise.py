@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .ref import DOT_PRECISION
+
 DEFAULT_BN = 256
 DEFAULT_BM = 256
 
@@ -32,7 +34,8 @@ def _pairwise_kernel(x_ref, c_ref, out_ref):
     cn = jnp.sum(c * c, axis=-1, keepdims=True)  # (bm, 1)   VPU
     # MXU matmul; accumulate in f32 regardless of input dtype.
     prod = jax.lax.dot_general(
-        x, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x, c, (((1,), (1,)), ((), ())), precision=DOT_PRECISION,
+        preferred_element_type=jnp.float32
     )                                            # (bn, bm)
     out_ref[...] = jnp.maximum(xn + cn.T - 2.0 * prod, 0.0)
 

@@ -10,7 +10,16 @@ a sqrt only when reporting radii).
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
+
+# The one matmul precision of every f32 distance dot (this oracle and the
+# Pallas tiles alike). On TPU an f32 dot at DEFAULT precision is a single
+# bf16 pass; HIGHEST asks for full f32 accumulation of f32 products, which
+# the |x|^2 + |c|^2 - 2 x.c decomposition needs (its cancellation amplifies
+# the product error by |x|^2 / d^2). On CPU every precision is f32, so
+# naming it moves no bits there.
+DOT_PRECISION = jax.lax.Precision.HIGHEST
 
 
 def dist2_to_center(x: jnp.ndarray, c: jnp.ndarray) -> jnp.ndarray:
@@ -28,7 +37,8 @@ def pairwise_dist2(x: jnp.ndarray, c: jnp.ndarray) -> jnp.ndarray:
     """
     xn = jnp.sum(x * x, axis=-1, keepdims=True)            # (n,1)
     cn = jnp.sum(c * c, axis=-1, keepdims=True).T          # (1,m)
-    d2 = xn + cn - 2.0 * jnp.dot(x, c.T, preferred_element_type=jnp.float32)
+    d2 = xn + cn - 2.0 * jnp.dot(x, c.T, precision=DOT_PRECISION,
+                                 preferred_element_type=jnp.float32)
     return jnp.maximum(d2, 0.0)
 
 

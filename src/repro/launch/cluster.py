@@ -105,11 +105,15 @@ class ClusterError(RuntimeError):
 
 def worker_env(num_local_devices: int = 1,
                extra: Optional[dict] = None) -> dict:
-    """Environment for one worker: pin the per-process CPU device count
-    (both the modern ``JAX_NUM_CPU_DEVICES`` spelling and the
-    ``XLA_FLAGS`` one the 0.4.x line honors) so the cluster topology is
-    ``num_processes × num_local_devices`` regardless of host cores."""
+    """Environment for one worker: pin the platform to CPU and the
+    per-process CPU device count, so the cluster topology is
+    ``num_processes × num_local_devices`` regardless of host cores. The
+    cluster is a CPU simulation by design: pinning the platform keeps a
+    worker off an accelerator that its parent process may hold."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    # The XLA flag too: appended last, it overrides a device count that an
+    # inherited XLA_FLAGS may carry.
     flags = env.get("XLA_FLAGS", "")
     flag = f"--xla_force_host_platform_device_count={num_local_devices}"
     env["XLA_FLAGS"] = (flags + " " + flag).strip()

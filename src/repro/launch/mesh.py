@@ -3,9 +3,8 @@
 IMPORTANT: functions only — importing this module never touches jax device
 state (the dry-run sets XLA_FLAGS before any jax initialization).
 
-Version portability: mesh construction goes through ``repro.compat``
-(``jax.sharding.AxisType`` exists only on jax 0.6+; on 0.4.x every axis is
-implicitly auto — see the support matrix in ``repro/compat.py``).
+Mesh construction goes through ``repro.compat.make_mesh`` (every axis
+explicitly *auto*).
 
 Multi-process: under ``jax.distributed`` the full device set is
 ``jax.devices()`` (global, ordered process-major) while this process can

@@ -12,11 +12,6 @@ jax = pytest.importorskip("jax")
 
 import harness  # noqa: E402
 
-from repro import compat  # noqa: E402
-
-pytestmark = pytest.mark.skipif(
-    not compat.HAS_DISTRIBUTED,
-    reason="this jax build has no jax.distributed runtime")
 
 
 def test_worker_dies_pre_initialize():

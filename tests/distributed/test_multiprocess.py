@@ -20,15 +20,10 @@ jax = pytest.importorskip("jax")
 import harness  # noqa: E402
 import scenarios  # noqa: E402
 
-from repro import compat  # noqa: E402
 from repro.core.eim import eim, eim_sample  # noqa: E402
 from repro.core.executor import HostStreamExecutor, SimExecutor  # noqa: E402
 from repro.core.mrg import mrg  # noqa: E402
 from repro.data import shard_source, synthetic_source  # noqa: E402
-
-pytestmark = pytest.mark.skipif(
-    not compat.HAS_DISTRIBUTED,
-    reason="this jax build has no jax.distributed runtime")
 
 # One parameter set per parity cell. eps/k are chosen so the EIM
 # sampling loop engages without covering everything (pop ≈ 0.6·n after

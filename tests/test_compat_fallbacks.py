@@ -1,15 +1,11 @@
-"""Pin repro.compat's feature-detection *fallback* branches.
+"""Pin repro.compat's wrappers over the installed jax line: shard_map's
+``check_replication`` spelling, auto-axis meshes, global-array assembly
+from per-shard host pieces, and the single-process forms of the
+multi-process helpers.
 
-The shim resolves every drifting jax API at import time via hasattr
-probes. The happy branch for the running jax line is exercised by the
-whole suite; these tests force each detection to MISS — by deleting the
-probed symbol and importing a fresh copy of the module — and pin that
-the legacy branch still produces the same public surface (and, for the
-global-assembly fallback, bitwise-identical arrays).
-
-A fresh module instance is loaded per test via spec_from_file_location:
-``importlib.reload`` would mutate the singleton other modules hold
-references to, leaking the monkeypatch beyond the test.
+A fresh module instance is loaded via spec_from_file_location where a
+test needs one: ``importlib.reload`` would mutate the singleton other
+modules hold references to.
 """
 import importlib.util
 from pathlib import Path
@@ -43,74 +39,26 @@ def one_device_mesh(mod):
 
 
 def test_fresh_load_matches_canonical_flags():
+    # A fresh instance builds the same mesh and ambient context as the
+    # canonical module (nothing in compat depends on import order).
     mod = load_fresh_compat()
-    assert mod.HAS_TOP_LEVEL_SHARD_MAP == canonical.HAS_TOP_LEVEL_SHARD_MAP
-    assert mod.HAS_AXIS_TYPE == canonical.HAS_AXIS_TYPE
-    assert mod.HAS_SET_MESH == canonical.HAS_SET_MESH
-    assert mod.HAS_GLOBAL_ASSEMBLY == canonical.HAS_GLOBAL_ASSEMBLY
-
-
-def test_missing_top_level_shard_map_uses_experimental(monkeypatch):
-    monkeypatch.delattr(jax, "shard_map", raising=False)
-    pytest.importorskip(
-        "jax.experimental.shard_map",
-        reason="this jax line has neither top-level nor experimental "
-               "shard_map")
-    mod = load_fresh_compat()
-    assert mod.HAS_TOP_LEVEL_SHARD_MAP is False
     mesh = one_device_mesh(mod)
-    f = mod.shard_map(mesh=mesh, in_specs=P(), out_specs=P(),
-                      check_replication=False)(lambda x: x * 2.0)
-    x = np.arange(4.0, dtype=np.float32)
-    np.testing.assert_array_equal(np.asarray(f(x)), x * 2.0)
-
-
-def test_missing_axis_type_builds_legacy_mesh(monkeypatch):
-    monkeypatch.delattr(jax.sharding, "AxisType", raising=False)
-    mod = load_fresh_compat()
-    assert mod.HAS_AXIS_TYPE is False
-    mesh = one_device_mesh(mod)
-    assert isinstance(mesh, jax.sharding.Mesh)
-    assert mesh.axis_names == ("data",)
-    assert mesh.devices.shape == (1,)
-
-
-def test_missing_set_mesh_uses_legacy_context(monkeypatch):
-    monkeypatch.delattr(jax, "set_mesh", raising=False)
-    mod = load_fresh_compat()
-    assert mod.HAS_SET_MESH is False
-    mesh = one_device_mesh(mod)
+    ref = one_device_mesh(canonical)
+    assert mesh.axis_names == ref.axis_names == ("data",)
+    assert mesh.axis_types == ref.axis_types == (
+        jax.sharding.AxisType.Auto,)
     with mod.set_mesh(mesh) as m:
         assert m is mesh
-        # the legacy `with mesh:` resource env is active: a NamedSharding
-        # built under it still resolves against this mesh
-        s = jax.sharding.NamedSharding(mesh, P("data"))
-        assert s.mesh.axis_names == ("data",)
-
-
-def test_missing_global_assembly_falls_back_to_device_put(monkeypatch):
-    pieces = [np.arange(12, dtype=np.float32).reshape(4, 3) + 100 * i
-              for i in range(len(jax.devices()[:1]))]
-    # canonical (assembly-API) reference, computed before the symbol is
-    # deleted — the fallback must be bitwise-identical to it
-    ref = np.asarray(canonical.global_array_from_shards(
-        one_device_mesh(canonical), P("data"), pieces))
-    monkeypatch.delattr(jax, "make_array_from_single_device_arrays",
-                        raising=False)
-    mod = load_fresh_compat()
-    assert mod.HAS_GLOBAL_ASSEMBLY is False
-    mesh = one_device_mesh(mod)
-    out = mod.global_array_from_shards(mesh, P("data"), pieces)
-    np.testing.assert_array_equal(np.asarray(out),
-                                  np.concatenate(pieces, axis=0))
-    np.testing.assert_array_equal(np.asarray(out), ref)
 
 
 def test_shard_map_replication_kwarg_resolved():
-    # whatever the line, the resolver must land on a known kwarg (or
-    # None on a hypothetical future line that dropped both)
-    assert canonical._CHECK_KW in ("check_vma", "check_rep", None)
+    # check_replication reaches jax.shard_map as check_vma, both as a
+    # direct call and as a decorator factory.
     mesh = one_device_mesh(canonical)
+    g = canonical.shard_map(mesh=mesh, in_specs=P(), out_specs=P(),
+                            check_replication=False)(lambda x: x * 2.0)
+    np.testing.assert_array_equal(np.asarray(g(np.ones((3,), np.float32))),
+                                  np.full((3,), 2.0, np.float32))
     f = canonical.shard_map(lambda x: x + 1.0, mesh=mesh, in_specs=P(),
                             out_specs=P(), check_replication=False)
     x = np.ones((3,), np.float32)
@@ -134,19 +82,6 @@ def test_global_assembly_rejects_none_local_piece():
     # a present piece still assembles when *it* is the only shard
     out = canonical.global_array_from_shards(mesh, P("data"), [good])
     np.testing.assert_array_equal(np.asarray(out), good)
-
-
-def test_global_assembly_fallback_rejects_none(monkeypatch):
-    # The host-concatenate fallback needs every row on this host — a
-    # None (remote) piece must be a hard error, not a silent zero-fill.
-    monkeypatch.delattr(jax, "make_array_from_single_device_arrays",
-                        raising=False)
-    mod = load_fresh_compat()
-    assert mod.HAS_GLOBAL_ASSEMBLY is False
-    mesh = one_device_mesh(mod)
-    good = np.zeros((4, 3), np.float32)
-    with pytest.raises(RuntimeError, match="needs every piece"):
-        mod.global_array_from_shards(mesh, P("data"), [good, None])
 
 
 def test_single_process_distributed_helpers():

@@ -1,15 +1,17 @@
 """R001 compat-only-imports.
 
-Contract: version-drifting jax APIs (``jax.experimental.shard_map``,
-top-level ``jax.shard_map``, ``jax.set_mesh``,
-``jax.make_array_from_single_device_arrays``, ``jax.sharding.AxisType``,
-``jax.experimental.multihost_utils``, ``jax.distributed``
-initialize/shutdown) are used *only* inside ``src/repro/compat.py`` — every other module goes
-through the feature-detected shim so the tree imports and runs on both
-the jax 0.4.x and 0.6+ CI lines.
+Contract: the jax APIs that ``src/repro/compat.py`` wraps
+(``jax.experimental.shard_map``, top-level ``jax.shard_map``,
+``jax.set_mesh``, ``jax.make_array_from_single_device_arrays``,
+``jax.sharding.AxisType``, ``jax.experimental.multihost_utils``,
+``jax.distributed`` initialize/shutdown) are used *only* inside it —
+every other module goes through the wrappers, so the behavior they add
+(``check_replication``, auto-axis meshes, per-shard assembly, the
+single-process fast paths) lives in one place and a future jax line is
+absorbed there.
 
 Pinned by: ARCHITECTURE.md "Version portability" and
-``tests/test_compat_fallbacks.py`` (the shim's legacy branches);
+``tests/test_compat_fallbacks.py`` (the wrappers on the installed line);
 the whitelist is ``config.WHITELIST["R001"]``.
 """
 from __future__ import annotations
