@@ -601,10 +601,8 @@ def _bernoulli_mask(key, n: int, p: np.float32, rows: int) -> np.ndarray:
     device working set is O(rows))."""
     parts = []
     for start in range(0, n, rows):
-        parts.append(np.asarray(engine.bernoulli_rows_block(
-            key, np.uint32(start & 0xFFFFFFFF),
-            np.uint32((start >> 32) & 0xFFFFFFFF),
-            min(rows, n - start), np.float32(p))))
+        parts.append(np.asarray(engine.bernoulli_rows(
+            key, start, min(rows, n - start), np.float32(p))))
     return (np.concatenate(parts) if parts
             else np.zeros((0,), bool))
 

@@ -95,17 +95,19 @@ def stream_update(state: StreamState, batch, *,
                             state.r, state.k)
     batch = np.asarray(batch, np.float32)
 
-    # bootstrap (only before the first doubling): the first k+1 points
-    # define the initial r; afterwards insertion always requires > 4r.
-    while r == 0.0 and count <= k and batch.size:
-        centers[count] = batch[0]
-        batch = batch[1:]
+    # bootstrap (only before the first doubling): the first k+1 distinct
+    # points define the initial r; afterwards insertion always requires
+    # > 4r. A row equal to a held center is covered and dropped, so r is
+    # half a positive distance; while at most k distinct points have
+    # arrived, r stays 0 and the sketch is exact.
+    while r == 0.0 and batch.size:
+        x, batch = batch[0], batch[1:]
+        if (centers[:count] == x).all(axis=1).any():
+            continue
+        centers[count] = x
         count += 1
         if count == k + 1:
-            # the (k+1, k+1) block is tiny; route through the façade so
-            # impl resolution stays in one place (kernels/engine.py)
-            d2 = np.array(ops.pairwise_dist2(
-                jnp.asarray(centers), jnp.asarray(centers)))
+            d2 = _pair_d2(centers)
             np.fill_diagonal(d2, np.inf)
             r = float(np.sqrt(d2.min())) / 2.0
             centers, count = _merge(centers, count, r, k)
@@ -162,17 +164,37 @@ def _insert_tail(batch: np.ndarray, dist: np.ndarray, centers: np.ndarray,
             count += 1
             if count == k + 1:
                 # classic doubling: never rest with more than k centers
-                r *= 2.0
-                centers, count = _merge(centers, count, r, k)
+                centers, count, r = _double(centers, count, r, k)
                 return batch[i + 1:], centers, count, r
             added.append(x.copy())
             if one_per_pass:
                 return batch[i + 1:], centers, count, r
         else:
-            r *= 2.0
-            centers, count = _merge(centers, count, r, k)
+            centers, count, r = _double(centers, count, r, k)
             return batch[i:], centers, count, r
     return batch[:0], centers, count, r
+
+
+def _double(centers: np.ndarray, count: int, r: float, k: int):
+    """Double r and re-cluster at the new scale. The bootstrap leaves r
+    positive before k+1 distinct centers can be held, and a doubling of 0
+    would never shrink the center set, so r <= 0 here is a broken
+    invariant, raised rather than spun on."""
+    if not r > 0.0:
+        raise RuntimeError(f"stream sketch doubling from r = {r}: the "
+                           f"bootstrap must leave r > 0")
+    r *= 2.0
+    centers, count = _merge(centers, count, r, k)
+    return centers, count, r
+
+
+def _pair_d2(live: np.ndarray) -> np.ndarray:
+    """(count, count) squared distances of a small host block, by exact
+    differences summed in f64: distinct rows are always at a positive
+    distance (the expanded |x|²+|y|²−2x·y form can round a close pair of
+    large-norm rows to 0)."""
+    diff = live[:, None, :] - live[None, :, :]
+    return np.einsum("ijk,ijk->ij", diff, diff, dtype=np.float64)
 
 
 def _merge(centers: np.ndarray, count: int, r: float, k: int):
@@ -181,8 +203,7 @@ def _merge(centers: np.ndarray, count: int, r: float, k: int):
     (count, count) distance block (count ≤ k+1) plus a masked greedy scan,
     no per-pair python distance loop."""
     live = centers[:count]
-    diff = live[:, None, :] - live[None, :, :]
-    d2 = np.einsum("ijk,ijk->ij", diff, diff)
+    d2 = _pair_d2(live)
     thr = (4.0 * r) ** 2
     ok = np.ones(count, bool)
     kept = []

@@ -471,20 +471,27 @@ def _uniform_rows_words(k0, k1, lo, hi, rows: int) -> jnp.ndarray:
     return (bits >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(2.0 ** -24)
 
 
+@functools.partial(jax.jit, static_argnames=("rows",))
+def _uniform_rows_block(key, start_lo, start_hi, rows: int):
+    k0, k1 = _key_words(key)
+    return _uniform_rows_words(k0, k1, start_lo, start_hi, rows)
+
+
 def uniform_rows(key, start: int, rows: int) -> jnp.ndarray:
     """Counter-based U[0,1) for absolute rows ``[start, start + rows)``.
 
     Row i's value depends only on ``(key, i)`` — never on ``start``'s
     blocking — so concatenating per-block calls over any partition of
     ``[0, n)`` is bitwise identical to one full-range call. ``start`` is a
-    host int (the 64-bit row index is split into uint32 counter words with
-    an explicit carry, so blocks may cross the 2^32 row boundary).
+    host int, split into uint32 counter words (with an explicit carry, so
+    blocks may cross the 2^32 row boundary) that reach one jitted program
+    per ``rows`` as operands — every block offset shares its compilation.
     """
     if rows < 0:
         raise ValueError(f"rows must be >= 0, got {rows}")
-    k0, k1 = _key_words(key)
-    return _uniform_rows_words(k0, k1, jnp.uint32(start & 0xFFFFFFFF),
-                               jnp.uint32((start >> 32) & 0xFFFFFFFF), rows)
+    return _uniform_rows_block(key, np.uint32(start & 0xFFFFFFFF),
+                               np.uint32((start >> 32) & 0xFFFFFFFF),
+                               rows=rows)
 
 
 def bernoulli_rows(key, start: int, rows: int, p) -> jnp.ndarray:
@@ -493,17 +500,6 @@ def bernoulli_rows(key, start: int, rows: int, p) -> jnp.ndarray:
     device fast path and the streamed path agree bitwise as long as they
     feed the same f32 ``p``."""
     return uniform_rows(key, start, rows) < jnp.asarray(p, jnp.float32)
-
-
-@functools.partial(jax.jit, static_argnames=("rows",))
-def bernoulli_rows_block(key, start_lo, start_hi, rows: int, p):
-    """Jitted ``bernoulli_rows`` for host-driven block loops: the 64-bit
-    block start arrives pre-split into two uint32 *operands* (``start_lo``,
-    ``start_hi``), so one compilation serves every block offset — the form
-    the streamed EIM's per-iteration mask generation uses."""
-    k0, k1 = _key_words(key)
-    u = _uniform_rows_words(k0, k1, start_lo, start_hi, rows)
-    return u < jnp.asarray(p, jnp.float32)
 
 
 def split_index_words(indices) -> tuple[np.ndarray, np.ndarray]:

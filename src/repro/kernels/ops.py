@@ -59,7 +59,6 @@ eim_filter_block = engine.eim_filter_block
 # the out-of-core EIM sampler is built on.
 uniform_rows = engine.uniform_rows
 bernoulli_rows = engine.bernoulli_rows
-bernoulli_rows_block = engine.bernoulli_rows_block
 split_index_words = engine.split_index_words
 uniform_rows_at = engine.uniform_rows_at
 bernoulli_rows_at = engine.bernoulli_rows_at

@@ -23,9 +23,10 @@ _HERE = os.path.dirname(os.path.abspath(__file__))
 SCENARIOS = os.path.join(_HERE, "scenarios.py")
 FAULTY_IMPORT = os.path.join(_HERE, "_faulty_import.py")
 
-# CI jobs give each cluster plenty of headroom but the workflow has a
-# hard job timeout; locally these all finish in seconds to ~a minute.
-DEFAULT_TIMEOUT = 300.0
+# Locally these all finish in seconds to ~a minute. The bound stays under
+# the per-test time limit (conftest.py, 300 s), so a stuck cluster fails
+# its own test with every worker's output.
+DEFAULT_TIMEOUT = 240.0
 
 
 def scenario_target(name: str) -> str:

@@ -13,7 +13,7 @@ import textwrap
 BASE = dict(PYTHONPATH="src")
 
 
-def _run(body: str, devices: int = 8, timeout: int = 600) -> str:
+def _run(body: str, devices: int = 8, timeout: int = 240) -> str:
     prog = textwrap.dedent(f"""
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={devices}"

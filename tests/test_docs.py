@@ -64,7 +64,7 @@ def test_quickstart_example_runs():
     out = subprocess.run(
         [sys.executable, os.path.join(REPO, "examples", "quickstart.py"),
          "--n", "20000"],
-        capture_output=True, text=True, env=env, timeout=600)
+        capture_output=True, text=True, env=env, timeout=240)
     assert out.returncode == 0, out.stderr[-3000:]
     for tag in ("GON", "MRG", "EIM", "out-of-core", "sharded"):
         assert tag in out.stdout, f"quickstart output lost its {tag} row"
@@ -82,7 +82,7 @@ def test_coreset_curation_example_runs():
         [sys.executable,
          os.path.join(REPO, "examples", "coreset_curation.py"),
          "--n", "4000"],
-        capture_output=True, text=True, env=env, timeout=600)
+        capture_output=True, text=True, env=env, timeout=240)
     assert out.returncode == 0, out.stderr[-3000:]
     for tag in ("curated", "random", "weighted coreset", "kz_center"):
         assert tag in out.stdout, f"curation output lost its {tag} row"

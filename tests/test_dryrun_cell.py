@@ -22,7 +22,7 @@ def test_dryrun_cell_compiles(tmp_path, arch, shape, mesh):
     out = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun", "--arch", arch,
          "--shape", shape, "--mesh", mesh, "--out", str(tmp_path)],
-        capture_output=True, text=True, env=env, timeout=560)
+        capture_output=True, text=True, env=env, timeout=240)
     assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]
     mesh_name = "pod16x16" if mesh == "single" else "pod2x16x16"
     path = tmp_path / mesh_name / f"{arch}__{shape}.json"

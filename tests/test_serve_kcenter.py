@@ -368,3 +368,66 @@ def test_host_tail_covers_every_streamed_point():
     centers, r = stream_result(st)
     _, d2 = _offline(pts, centers)
     assert float(np.sqrt(d2).max()) <= 4.0 * r + 1e-4
+
+
+# ---------------------------------------------------------------------------
+# bootstrap: duplicate rows never leave r at 0 once k+1 distinct points
+# have arrived (a doubling of r = 0 would never shrink the center set)
+# ---------------------------------------------------------------------------
+
+def _with_early_duplicates(k, d, n, seed, gap=0.0):
+    """Points whose first k+1 rows hold duplicates — exact at ``gap`` 0,
+    else ``gap`` apart at a norm where the expanded |x|²+|y|²−2x·y form
+    rounds their distance to 0 — then more than k distinct points."""
+    rng = np.random.default_rng(seed)
+    pts = rng.normal(size=(n, d)).astype(np.float32)
+    pts *= np.linspace(1.0, 20.0, n, dtype=np.float32)[:, None]
+    pts += np.float32(100.0)
+    for dup, orig in ((2, 0), (4, 0), (5, 3)):
+        pts[dup] = pts[orig]
+        pts[dup, 0] += np.float32(gap)
+    return pts
+
+
+@pytest.mark.time_limit(30)
+@pytest.mark.parametrize("gap", [0.0, 0.03], ids=["exact", "near"])
+@pytest.mark.parametrize("tail", ["host", "device"])
+def test_duplicates_in_bootstrap_finish_with_positive_radius(tail, gap):
+    k, d = 6, 4
+    pts = _with_early_duplicates(k, d, 600, seed=1, gap=gap)
+    cuts = [0, 3, 100, 200, 300, 400, 500, 600]   # bootstrap spans calls
+    st = stream_init(k, d)
+    for lo, hi in zip(cuts, cuts[1:]):
+        st = stream_update(st, pts[lo:hi], tail=tail)
+        assert st.count <= k + 1
+    centers, r = stream_result(st)
+    assert r > 0
+    assert 1 < centers.shape[0] <= k
+    diff = centers[:, None, :] - centers[None, :, :]
+    dist = np.sqrt((diff ** 2).sum(-1))
+    np.fill_diagonal(dist, np.inf)
+    assert dist.min() > r
+
+
+@pytest.mark.time_limit(30)
+@pytest.mark.parametrize("tail", ["host", "device"])
+def test_identical_rows_keep_one_center_at_zero_radius(tail):
+    k, d = 4, 3
+    pts = np.full((5000, d), 7.5, np.float32)
+    st = stream_update(stream_init(k, d), pts, tail=tail)
+    st = stream_update(st, pts[:10], tail=tail)
+    centers, r = stream_result(st)
+    assert r == 0.0
+    np.testing.assert_array_equal(centers, pts[:1])
+
+
+@pytest.mark.time_limit(30)
+def test_service_ingest_of_duplicate_rows_drains():
+    k, d = 6, 4
+    pts = _with_early_duplicates(k, d, 400, seed=2, gap=0.03)
+    with KCenterService(k, d) as svc:
+        svc.submit_points(pts)
+        svc.drain(timeout=20)
+        _, centers, r = svc.snapshot()
+    assert r > 0
+    assert 1 < centers.shape[0] <= k

@@ -89,7 +89,7 @@ RAGGED_STREAMS: Tuple[str, ...] = (
 # tail blocks would recompile per tail shape, so R004 demands the
 # pad-to-fixed-rows dance wherever a ragged stream reaches them.
 JITTED_CALLEES: Tuple[str, ...] = (
-    "bernoulli_rows_block", "bernoulli_rows_at_block",
+    "bernoulli_rows_at_block",
     "eim_filter_block", "_eim_filter_block",
     "fused_filter_blocks", "fused_assign_blocks", "fused_argmin_blocks",
     # The weighted sibling of fused_filter_blocks (one extra (bn,) weight
