@@ -216,18 +216,27 @@ def _vmapped(fn: BlockFn):
     return jax.jit(jax.vmap(fn))
 
 
-@functools.partial(jax.jit, static_argnums=1)
-def _block(points: jnp.ndarray, m: int):
-    """Pad & reshape (n,d) -> (m, ceil(n/m), d) plus validity mask.
-
-    Jitted: eagerly, a pad (even a zero-width one) and a reshape each copy
-    the points, and three copies of a 6.4 GB input do not fit one chip."""
+def _blocked(points: jnp.ndarray, m: int):
+    """Pad & reshape (n,d) -> (m, ceil(n/m), d) plus validity mask: the
+    paper's contiguous ``ceil(n/m)``-row machine blocks, the last one
+    padded and masked off. When ``m`` divides ``n`` there is no pad, and
+    traced inside a jit the reshape is a view of the points (a bitcast)."""
     n, d = points.shape
     per = -(-n // m)
     pad = per * m - n
-    pts = jnp.pad(points, ((0, pad), (0, 0)))
+    pts = jnp.pad(points, ((0, pad), (0, 0))) if pad else points
     mask = jnp.arange(per * m) < n
     return pts.reshape(m, per, d), mask.reshape(m, per)
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _block(points: jnp.ndarray, m: int):
+    """``_blocked`` as its own program: its output is a new buffer, a copy
+    of the points (eagerly a pad and a reshape would make one each). Round
+    1 over a corpus blocks inside its own program instead (``_round1``);
+    this serves the small center unions ``combine`` re-blocks and EIM's
+    cached blocking (``SimExecutor._blocked_for``)."""
+    return _blocked(points, m)
 
 
 def _run_round(points_blocked: jnp.ndarray, mask_blocked: jnp.ndarray,
@@ -251,6 +260,29 @@ def _run_round_w(points_blocked: jnp.ndarray, mask_blocked: jnp.ndarray,
     any_valid = jnp.any(mask_blocked, axis=1)
     valid = jnp.repeat(any_valid, k)
     return centers.reshape(m * k, -1), valid, cw.reshape(-1)
+
+
+@functools.lru_cache(maxsize=None)
+def _round1(fn, m: int):
+    """Round 1 over ``m`` simulated machines as one jitted program, built
+    once per ``(fn, m)``. Its argument is the caller's ``(n, d)`` points
+    (and, for a ``WeightedBlockFn``, their ``(n,)`` weights): the machine
+    blocking happens inside, so when ``m`` divides ``n`` the blocks are a
+    view of the argument and no blocked copy of the corpus exists. When
+    ``m`` does not divide ``n`` the padded blocks are a copy inside the
+    program, the same contiguous ``ceil(n/m)``-row blocks either way.
+    Returns ``_run_round``'s (or, weighted, ``_run_round_w``'s) outputs."""
+    def run(points, w=None):
+        blocked, mask = _blocked(points, m)
+        # Without the barrier the TPU compiler folds the block view into
+        # GON's first distance pass and materializes the first center,
+        # broadcast, over the whole corpus.
+        blocked = jax.lax.optimization_barrier(blocked)
+        if w is None:
+            return _run_round(blocked, mask, fn)
+        wb = jnp.pad(w, (0, mask.size - w.shape[0])).reshape(mask.shape)
+        return _run_round_w(blocked, mask, wb, fn)
+    return jax.jit(run)
 
 
 def _mrg_round(points_blocked: jnp.ndarray, mask_blocked: jnp.ndarray,
@@ -496,7 +528,9 @@ class Executor:
 class SimExecutor(Executor):
     """The paper's experimental setup (§7.1): ``m`` simulated machines on
     one device — the source is materialized and blocked into m shards, and
-    GON runs on every shard via ``vmap``."""
+    GON runs on every shard via ``vmap``. Round 1 blocks inside its own
+    program (``_round1``): with ``m`` dividing n the shards are a view of
+    the device array, and no copy of it is made."""
 
     def __init__(self, m: int = 50):
         if m < 1:
@@ -506,12 +540,10 @@ class SimExecutor(Executor):
     def run_blocks(self, fn, source, *, objective: Objective | None = None):
         src = as_source(source)
         x = src.materialize()
-        blocked, mask = _block(x, self.m)
         if objective is not None and objective.weighted:
-            w = jnp.asarray(weights_of(src, 0, src.n))
-            wb = jnp.pad(w, (0, mask.size - w.shape[0]))
-            return _run_round_w(blocked, mask, wb.reshape(mask.shape), fn)
-        return _run_round(blocked, mask, fn)
+            return _round1(fn, self.m)(x, jnp.asarray(weights_of(src, 0,
+                                                                 src.n)))
+        return _round1(fn, self.m)(x)
 
     def default_capacity(self, source, k: int) -> int:
         return max(-(-source.n // self.m), 2 * k)
@@ -756,8 +788,9 @@ class MeshExecutor(Executor):
       (all_gather of center sets + replicated GON; with ``hierarchical``,
       axis-by-axis gathers with an intermediate GON per level, exactly
       Lemma 3 with ICI-domain capacities) and the radius reduction, with
-      no host round-trips. The input is materialized then resharded, so
-      n is bounded by single-host RAM — the historical behavior.
+      no host round-trips, jitted once per executor and shape. The input
+      is materialized then resharded (a device array already sharded
+      over the mesh axes is used as it is).
     * **Sharded / streamed** (a ``ShardedSource``, or any host/disk/
       generator source — auto-split by ``shard_source`` into the paper's
       contiguous machine ranges): round 1 streams each shard's blocks
@@ -1209,16 +1242,20 @@ class MeshExecutor(Executor):
         return super().mrg(src, k, capacity=capacity, impl=impl, chunk=chunk,
                            objective=objective)
 
-    def _mrg_fused(self, source, k: int, *, impl: str = "auto",
-                   chunk: int | None = None):
+    def _fused_step(self, k: int, impl: str, chunk: int | None):
+        """The fused device-path program, jitted and cached per ``(k,
+        impl, chunk)`` and blocking: its argument is the sharded corpus as
+        it is, so no job copies it or traces the program again."""
         axes = self.shard_axes
         hierarchical = self.hierarchical
-        pspec = self._pspec()
+        key = ("fused", k, impl, chunk, axes, hierarchical)
+        if key in self._step_cache:
+            return self._step_cache[key]
 
         @functools.partial(
             compat.shard_map,
             mesh=self.mesh,
-            in_specs=(pspec,),
+            in_specs=(self._pspec(),),
             out_specs=(P(), P()),
             check_replication=False,
         )
@@ -1241,9 +1278,14 @@ class MeshExecutor(Executor):
                 r2 = jax.lax.pmax(r2, ax)
             return centers, r2
 
+        self._step_cache[key] = jax.jit(run)
+        return self._step_cache[key]
+
+    def _mrg_fused(self, source, k: int, *, impl: str = "auto",
+                   chunk: int | None = None):
         x = as_source(source).materialize()
-        sharding = NamedSharding(self.mesh, pspec)
-        x = jax.device_put(x, sharding)
-        centers, r2 = run(x)
-        rounds = 1 + (len(axes) if hierarchical and len(axes) > 1 else 1)
+        x = jax.device_put(x, NamedSharding(self.mesh, self._pspec()))
+        centers, r2 = self._fused_step(k, impl, chunk)(x)
+        axes = self.shard_axes
+        rounds = 1 + (len(axes) if self.hierarchical and len(axes) > 1 else 1)
         return centers, r2, rounds

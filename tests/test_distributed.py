@@ -106,6 +106,55 @@ def test_mesh_executor_hierarchical_vs_flat():
     assert r["ratio_h"] <= 8.0 and r["ratio_f"] <= 4.0
 
 
+def test_mesh_fused_program_built_once_and_bitwise_eager():
+    """The fused device-path program is jitted and cached on the
+    executor: two jobs on one shape build it once, and give bitwise the
+    same centers and radius as each other and as the same ``shard_map``
+    body run eagerly (how the program ran before it was staged)."""
+    out = _run("""
+        import functools
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro import compat
+        from repro.core import MeshExecutor, gonzalez
+        from repro.data import ArraySource
+        from repro.kernels import ops
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("data",))
+        n, d, k = 4096, 24, 12
+        x = np.random.default_rng(4).normal(size=(n, d)).astype(np.float32)
+        xs = jax.device_put(x, NamedSharding(mesh, P("data")))
+        cells = {}
+        for impl in ("ref", "pallas"):
+            @functools.partial(compat.shard_map, mesh=mesh,
+                               in_specs=(P("data"),), out_specs=(P(), P()),
+                               check_replication=False)
+            def eager(local):
+                centers = gonzalez(local, k, impl=impl).centers
+                centers = jax.lax.all_gather(centers, "data", tiled=True)
+                centers = gonzalez(centers, k, impl=impl).centers
+                _, d2 = ops.assign_nearest(local, centers, impl=impl)
+                return centers, jax.lax.pmax(jnp.max(d2), "data")
+            ce, r2e = eager(xs)
+            ex = MeshExecutor(mesh)
+            runs = [ex.mrg(ArraySource(xs), k, impl=impl) for _ in range(2)]
+            fused = [key for key in ex._step_cache if key[0] == "fused"]
+            cells[impl] = {
+                "programs": len(fused),
+                "compiled": ex._step_cache[fused[0]]._cache_size(),
+                "rounds": [r for _, _, r in runs],
+                "same": [bool((np.asarray(c) == np.asarray(ce)).all()
+                              and np.asarray(r2).tobytes()
+                              == np.asarray(r2e).tobytes())
+                         for c, r2, _ in runs]}
+        print(json.dumps(cells))
+    """, devices=4)
+    cells = json.loads(out.strip().splitlines()[-1])
+    for impl, c in cells.items():
+        assert c["programs"] == 1 and c["compiled"] == 1, (impl, c)
+        assert c["rounds"] == [2, 2], (impl, c)
+        assert c["same"] == [True, True], (impl, c)
+
+
 def test_sharded_source_mesh_mrg_bitwise_parity_grid():
     """The tentpole contract: ``mrg`` over a ``ShardedSource`` on the
     streamed ``MeshExecutor`` is *bitwise identical* to the

@@ -133,3 +133,21 @@ def test_large_center_set_leaves_the_fused_tile(native):
     fused, interpret, _ = engine._stream_tiles("auto", None, N, 240_000, D)
     assert not fused and not interpret
     assert engine._stream_tiles("auto", None, N, M, D)[0]
+
+
+def test_sim_round1_blocks_without_a_copy(one_chip, native):
+    # SimExecutor's round 1 at the one-chip benchmark's blocking (16
+    # machines, k = 1,024): the program takes the corpus, and no buffer of
+    # its size exists inside either. Without the optimization barrier the
+    # compiler folds the block view into the first distance pass and
+    # materializes the first center broadcast over the whole corpus.
+    from repro.core.executor import _round1, gon_block_fn
+    n, machines = 16 * 16384, 16
+    compiled = _round1(gon_block_fn(M, "auto", None), machines).lower(
+        _spec(one_chip, (n, D))).compile()
+    mem = compiled.memory_analysis()
+    corpus = n * D * 4
+    assert mem.argument_size_in_bytes == corpus
+    assert mem.temp_size_in_bytes < corpus // 64
+    assert mem.output_size_in_bytes < corpus // 8      # the m·k union
+    assert "tpu_custom_call" in compiled.as_text()
